@@ -107,6 +107,9 @@ def main() -> None:
                          "us_per_call regression for any matching row name")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import paper_benchmarks as pb
     fns = [pb.smoke] if args.smoke else [
         fn for fn in pb.ALL

@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.core.engine import EngineConfig, ShardedSummarizer
 from repro.core.engine.state import OBJECTIVES, PROPOSALS
-from repro.dist.router import DEFAULT_REPLICA_EXEC
+from repro.dist.router import default_replica_exec
 from repro.ft.inject import SimulatedCrash, drive
 from repro.graph.streams import (barabasi_albert_edges,
                                  edges_to_fully_dynamic_stream)
@@ -76,7 +76,7 @@ def make_engine(checkpoint_dir=None):
 ss = make_engine(ckpt_dir)
 assert ss.routing == "device" and ss.sync_free and ss.pipeline
 # the constructor resolves replica_exec=None to the backend-aware default
-assert ss.replica_exec == DEFAULT_REPLICA_EXEC
+assert ss.replica_exec == default_replica_exec()
 print(f"router: chunk={ss.router_chunk} lane_cap={ss.lane_cap} "
       f"sync_free={ss.sync_free} pipeline={ss.pipeline} "
       f"replica_exec={ss.replica_exec}")

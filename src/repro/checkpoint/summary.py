@@ -35,8 +35,9 @@ break bitwise replay, which is worse.  Execution *variants* that are
 leaf-bitwise state-identical by the standing differential bar —
 ``replica_exec``, ``trial_backend``, ``routing``, mesh topology — are
 recorded informationally but NOT pinned: a checkpoint taken on an
-8-device mesh restores onto 1 device (same ``n_shards``; the next
-dispatch reshards under the live mesh), which is the elastic leg.
+8-device mesh restores onto 1 device (same ``n_shards``; every leaf is
+placed under the live mesh's shardings as it is read), which is the
+elastic leg.
 
 Retention: the newest :data:`KEEP_EPOCHS` checkpoints are kept and the
 journal is compacted to the *oldest* retained checkpoint's sequence
@@ -138,7 +139,8 @@ def restore_summarizer(summ, ckpt_dir: str,
             continue
         extra = checkpointer.load_meta(ckpt_dir, s).get("extra", {})
         _check_manifest(summ, extra)
-        tree = checkpointer.restore(ckpt_dir, s, like=summ._ckpt_tree())
+        tree = checkpointer.restore(ckpt_dir, s, like=summ._ckpt_tree(),
+                                    shardings=summ._ckpt_shardings())
         host = pickle.loads(checkpointer.load_blob(ckpt_dir, s, "host.pkl"))
         summ._ckpt_apply(tree, host, extra)
         return dict(step=s, epoch=int(extra["epoch"]),

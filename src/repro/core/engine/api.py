@@ -242,6 +242,11 @@ class _CrashConsistency:
                 "the summarizer with checkpoint_dir=...")
         return d
 
+    def _ckpt_shardings(self):
+        """Placement of the restored tree's leaves (``None``: default
+        device)."""
+        return None
+
     def save(self, ckpt_dir: Optional[str] = None) -> str:
         """Checkpoint the full recovery closure at a flushed epoch."""
         from repro.checkpoint import summary as ckpt
@@ -530,10 +535,11 @@ class ShardedSummarizer(_CrashConsistency):
       device.  Also the differential reference, like ``routing="host"``:
       both modes are leaf-bitwise state-identical on identical inputs.
 
-    The default (``repro.dist.router.DEFAULT_REPLICA_EXEC``) is
-    backend-aware: vmap on accelerators, map on the XLA CPU backend,
-    where batched control flow carries a measured fixed dispatch tax
-    (see docs/KNOWN_ISSUES.md).  ``REPRO_REPLICA_EXEC`` overrides.
+    The default (``repro.dist.router.default_replica_exec()``, resolved
+    here rather than at import) is backend-aware: vmap on accelerators,
+    map on the XLA CPU backend, where batched control flow carries a
+    measured fixed dispatch tax (see docs/KNOWN_ISSUES.md).
+    ``REPRO_REPLICA_EXEC`` overrides.
 
     **Probe backend** (``trial_backend=``): how the engine's batched
     hash-table probes (trial lookups + the router's intern pre-lookup)
@@ -579,7 +585,6 @@ class ShardedSummarizer(_CrashConsistency):
         import math
 
         import jax
-        import jax.numpy as jnp
 
         from repro.dist import router as dist_router
 
@@ -589,7 +594,7 @@ class ShardedSummarizer(_CrashConsistency):
             cfg = dataclasses.replace(cfg, **overrides)
         self.cfg = cfg
         if replica_exec is None:
-            replica_exec = dist_router.DEFAULT_REPLICA_EXEC
+            replica_exec = dist_router.default_replica_exec()
         if replica_exec not in dist_router.REPLICA_EXEC_MODES:
             raise ValueError(
                 f"replica_exec must be one of "
@@ -661,18 +666,8 @@ class ShardedSummarizer(_CrashConsistency):
         self._epoch = 0             # engine dispatches applied to self.state
         self._init_crash_consistency(checkpoint_dir)
 
-        state1 = new_state(cfg)
-        n = self.n_shards
-        stacked = jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), state1)
-        # decorrelate the per-shard trial PRNG streams
-        stacked = stacked._replace(
-            step_no=jnp.uint32(cfg.seed)
-            + jnp.arange(n, dtype=jnp.uint32) * jnp.uint32(2654435761))
-        self.state = stacked
-        ist1 = dist_router.intern_new(cfg)
-        self.intern = jax.tree.map(
-            lambda l: jnp.broadcast_to(l[None], (n,) + l.shape), ist1)
+        self.state, self.intern = dist_router.new_stacked_states(
+            cfg, mesh, self.n_shards)
 
         self._h2label: Dict[int, object] = {}  # 62-bit hash -> caller label
         self._label_buf: List = []   # (labels, hi, lo) pending lazy fold
@@ -1104,6 +1099,12 @@ class ShardedSummarizer(_CrashConsistency):
     # ----------------------------------------------------- recovery closure
     def _ckpt_tree(self) -> dict:
         return {"est": self.state._asdict(), "ist": self.intern._asdict()}
+
+    def _ckpt_shardings(self) -> dict:
+        # every restored leaf goes straight to its shard's device
+        from repro.dist import router as dist_router
+        est, ist = dist_router.state_shardings(self.cfg, self.mesh)
+        return {"est": est._asdict(), "ist": ist._asdict()}
 
     def _ckpt_host(self) -> dict:
         # host_label_map() is the sync point: drains the pipeline and folds

@@ -27,9 +27,11 @@ from typing import List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-EMPTY = jnp.int32(-1)
-TOMB = jnp.int32(-2)
+# numpy scalars: a jnp constant here would start a backend at import
+EMPTY = np.int32(-1)
+TOMB = np.int32(-2)
 
 # ---------------------------------------------------------------------- #
 # batched-probe backend switch

@@ -16,10 +16,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.engine.hashtable import HashTable, ht_new
 
-NO_CLUSTER = jnp.int32(0x7FFFFFFF)
+NO_CLUSTER = np.int32(0x7FFFFFFF)   # numpy: no backend at import
 
 # Canonical policy names.  The implementations live in
 # ``repro.core.engine.policies`` (which imports this module, so only the

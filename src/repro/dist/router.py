@@ -100,8 +100,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.engine.hashtable import (HashTable, ht_find, ht_find_batch,
                                          ht_new, ht_set,
@@ -110,7 +110,7 @@ from repro.core.engine.hashtable import (HashTable, ht_find, ht_find_batch,
 from repro.core.engine.state import EngineConfig, new_state
 from repro.core.engine.trial import pwhen, step_fn
 
-INVALID = jnp.int32(-1)
+INVALID = np.int32(-1)   # numpy: importing the router starts no backend
 
 # the device shard key is (h_hi * 2**31 + h_lo) % n_shards computed in
 # uint32 residues; (n-1)**2 + (n-1) must stay below 2**31
@@ -138,9 +138,14 @@ MAX_SHARDS = 1 << 15
 # is pure performance; REPRO_REPLICA_EXEC overrides (the CI router-stress
 # job uses it to cover both).
 REPLICA_EXEC_MODES = ("vmap", "map")
-DEFAULT_REPLICA_EXEC = os.environ.get(
-    "REPRO_REPLICA_EXEC",
-    "map" if jax.default_backend() == "cpu" else "vmap")
+
+
+def default_replica_exec() -> str:
+    """The replica layout for the running backend, resolved at call time
+    (never at import, so importing ``repro`` initialises no backend)."""
+    return os.environ.get(
+        "REPRO_REPLICA_EXEC",
+        "map" if jax.default_backend() == "cpu" else "vmap")
 
 
 def _replica_apply(fn, replica_exec: str, *stacked):
@@ -204,7 +209,6 @@ def drain_telemetry_new(n_dev: int) -> jax.Array:
 def drain_telemetry_restore(saved, n_dev: int) -> jax.Array:
     """Re-broadcast a saved (mesh-uniform) drain-round vector onto a mesh
     of ``n_dev`` devices; bitwise-identical when the topology matches."""
-    import numpy as np
     count = jnp.int32(np.max(np.asarray(saved))) if np.size(saved) else 0
     return jnp.full((n_dev,), count, jnp.int32)
 
@@ -341,16 +345,40 @@ def _state_specs(cfg: EngineConfig, axis: str):
             jax.tree.map(lambda _: P(axis), ist_sds))
 
 
-def _donate_argnums(*argnums: int) -> tuple:
-    """Donate the given buffers where the backend supports it.
+def state_shardings(cfg: EngineConfig, mesh):
+    """``(EngineState, InternState)`` trees of ``NamedSharding``: each
+    stacked leaf split over the mesh's shard axis, as the steps expect."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        _state_specs(cfg, mesh.axis_names[0]),
+                        is_leaf=lambda x: isinstance(x, P))
+
+
+def new_stacked_states(cfg: EngineConfig, mesh, n_shards: int):
+    """Fresh stacked ``(EngineState, InternState)`` for ``n_shards`` shards,
+    built in place over ``mesh``: every device materializes only its own
+    shards (no full stack on one device before the first step reshards)."""
+    def build():
+        stack = lambda l: jnp.broadcast_to(l[None], (n_shards,) + l.shape)  # noqa: E731
+        est = jax.tree.map(stack, new_state(cfg))
+        # decorrelate the per-shard trial PRNG streams
+        est = est._replace(
+            step_no=jnp.uint32(cfg.seed)
+            + jnp.arange(n_shards, dtype=jnp.uint32) * jnp.uint32(2654435761))
+        return est, jax.tree.map(stack, intern_new(cfg))
+
+    return jax.jit(build, out_shardings=state_shardings(cfg, mesh))()
+
+
+def _donate_argnums(mesh, *argnums: int) -> tuple:
+    """Donate the given buffers where the mesh's platform supports it.
 
     Donation lets XLA update the (large) stacked engine states — and the
     pipeline's double-buffered routing buckets — in place, so the host can
     stage chunk k+1 while chunk k computes without doubling device memory.
-    The CPU backend ignores donation (and warns), so gate on the backend
-    instead of spamming every jit call site.
+    The CPU backend ignores donation (and warns), so gate on the platform
+    of the devices the step is compiled for.
     """
-    return () if jax.default_backend() == "cpu" else argnums
+    return () if mesh.devices.flat[0].platform == "cpu" else argnums
 
 
 # compiled-step memo: ShardedSummarizer constructions with identical
@@ -362,7 +390,7 @@ _STEP_CACHE: dict = {}
 
 
 def make_bucketed_step(cfg: EngineConfig, mesh,
-                       replica_exec: str = DEFAULT_REPLICA_EXEC,
+                       replica_exec: Optional[str] = None,
                        trial_backend: Optional[str] = None):
     """jit(shard_map) step consuming host-bucketed ``[n_shards, batch]``
     hash-word rounds.  Bucketing/packing happens on the host; interning and
@@ -373,6 +401,7 @@ def make_bucketed_step(cfg: EngineConfig, mesh,
     lower per ``trial_backend`` (resolved against the
     ``REPRO_TRIAL_BACKEND`` default).  Memoized on
     ``(cfg, mesh, replica_exec, trial_backend)``."""
+    replica_exec = replica_exec or default_replica_exec()
     trial_backend = resolve_trial_backend(trial_backend)
     key = ("bucketed", cfg, mesh, replica_exec, trial_backend)
     if key in _STEP_CACHE:
@@ -392,11 +421,11 @@ def make_bucketed_step(cfg: EngineConfig, mesh,
             return _replica_apply(one, replica_exec,
                                   est, ist, uh, ul, vh, vl, ins)
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(est_specs, ist_specs) + (P(axis),) * 5,
-        out_specs=(est_specs, ist_specs), check_rep=False),
-        donate_argnums=_donate_argnums(0, 1))
+        out_specs=(est_specs, ist_specs), check_vma=False),
+        donate_argnums=_donate_argnums(mesh, 0, 1))
     _STEP_CACHE[key] = fn
     return fn
 
@@ -560,10 +589,10 @@ def make_route_step(mesh, n_shards: int, chunk: int, lane_cap: int,
         return (acc[..., 0], acc[..., 1], acc[..., 2], acc[..., 3],
                 acc[..., 4], counts, delivered[None], rounds[None])
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis),) * 5,
-        out_specs=(P(axis),) * 8, check_rep=False))
+        out_specs=(P(axis),) * 8, check_vma=False))
     _STEP_CACHE[key] = (fn, geom)
     return fn, geom
 
@@ -574,7 +603,7 @@ def make_route_step(mesh, n_shards: int, chunk: int, lane_cap: int,
 
 
 def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
-                     replica_exec: str = DEFAULT_REPLICA_EXEC,
+                     replica_exec: Optional[str] = None,
                      trial_backend: Optional[str] = None):
     """Compile the state-carrying engine stage for routed buckets.
 
@@ -597,6 +626,7 @@ def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
     Memoized on ``(cfg, mesh, n_shards, acc_cap, replica_exec,
     trial_backend)``.
     """
+    replica_exec = replica_exec or default_replica_exec()
     trial_backend = resolve_trial_backend(trial_backend)
     key = ("engine", cfg, mesh, n_shards, acc_cap, replica_exec,
            trial_backend)
@@ -658,11 +688,11 @@ def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
         # accumulated device-side (rounds is mesh-uniform by construction)
         return est, ist, telem + rounds - 1
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(est_specs, ist_specs) + (P(axis),) * 8,
-        out_specs=(est_specs, ist_specs, P(axis)), check_rep=False),
-        donate_argnums=_donate_argnums(0, 1, 2, 3, 4, 5, 6, 7))
+        out_specs=(est_specs, ist_specs, P(axis)), check_vma=False),
+        donate_argnums=_donate_argnums(mesh, 0, 1, 2, 3, 4, 5, 6, 7))
     _STEP_CACHE[key] = fn
     return fn
 

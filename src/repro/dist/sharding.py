@@ -161,8 +161,6 @@ def data_parallel(fn, mesh: Mesh):
     ``fn`` must be shardwise-independent: no cross-batch reductions, each
     output carries the global batch on dim 0.
     """
-    from jax.experimental.shard_map import shard_map
-
     ax = batch_axes(mesh) or tuple(mesh.axis_names)
     spec = P(ax if len(ax) > 1 else ax[0])
     # keyed on (treedef, leaf avals): grows like a jit cache, one entry per
@@ -178,8 +176,8 @@ def data_parallel(fn, mesh: Mesh):
             out_sds = jax.eval_shape(fn, *args)
             in_specs = jax.tree.map(lambda _: spec, args)
             out_specs = jax.tree.map(lambda _: spec, out_sds)
-            sm = jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                   out_specs=out_specs, check_rep=False))
+            sm = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                       out_specs=out_specs, check_vma=False))
             cache[key] = sm
         return sm(*args)
 

@@ -45,7 +45,7 @@ def _kernel(row_off_ref, senders_ref, dst_loc_ref, x_ref, out_ref, *,
         acc, cnt = carry
         src = senders_ref[e]
         loc = dst_loc_ref[e]
-        row = pl.load(x_ref, (pl.dslice(src, 1), slice(None)))  # [1, bf]
+        row = x_ref[pl.ds(src, 1), :]  # [1, bf]
         onehot = (jax.lax.iota(jnp.int32, bn) == loc)[:, None]  # [bn, 1]
         cnt = cnt + onehot.astype(jnp.int32)
         if reduce == "sum":

@@ -11,6 +11,7 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # hardware constants used by the roofline analysis (TPU v5e)
 PEAK_FLOPS_BF16 = 197e12       # per chip
@@ -21,13 +22,14 @@ ICI_BW = 50e9                  # bytes/s per link
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever devices exist locally, as a 1-D ('data',) mesh (tests/CPU)."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
 
 
 def make_engine_mesh(n_devices: int | None = None):

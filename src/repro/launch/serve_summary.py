@@ -27,7 +27,8 @@ from typing import Sequence
 import numpy as np
 
 from repro.core.engine import EngineConfig, ShardedSummarizer
-from repro.dist.router import DEFAULT_REPLICA_EXEC, REPLICA_EXEC_MODES
+from repro.dist.router import REPLICA_EXEC_MODES
+from repro.launch.cache import enable_compile_cache
 from repro.launch.stream import make_stream
 
 
@@ -134,7 +135,8 @@ def main() -> None:
                     help="serial route/engine dispatch: every snapshot "
                          "then sits exactly at the write head (lag 0)")
     ap.add_argument("--replica-exec", choices=list(REPLICA_EXEC_MODES),
-                    default=DEFAULT_REPLICA_EXEC)
+                    default=None,
+                    help="default: vmap on accelerators, map on the CPU")
     ap.add_argument("--reads-per-chunk", type=int, default=64)
     ap.add_argument("--verify", action="store_true",
                     help="differentially check every sampled read against "
@@ -143,6 +145,7 @@ def main() -> None:
     ap.add_argument("--escape", type=float, default=dflt.escape)
     ap.add_argument("--batch", type=int, default=dflt.batch)
     args = ap.parse_args()
+    enable_compile_cache()
 
     stream = make_stream(args.graph, args.nodes, args.deg, args.beta,
                          args.fully_dynamic, args.seed)

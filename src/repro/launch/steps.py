@@ -222,8 +222,6 @@ def build_mosso(spec: ArchSpec, cell: ShapeCell, mesh: Mesh,
         state1)
     ch_sh = NamedSharding(mesh, P(axes))
 
-    from jax.experimental.shard_map import shard_map
-
     def local_step(st, u, v, ins):
         st0 = jax.tree.map(lambda x: x[0], st)
         st1 = step_fn(st0, u[0], v[0], ins[0], cfg)
@@ -232,12 +230,12 @@ def build_mosso(spec: ArchSpec, cell: ShapeCell, mesh: Mesh,
         out = jax.tree.map(lambda x: x[None], st1)
         return out, phi[None]
 
-    dist_step = shard_map(
+    dist_step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axes), state1), P(axes), P(axes),
                   P(axes)),
         out_specs=(jax.tree.map(lambda _: P(axes), state1), P(axes)),
-        check_rep=False)
+        check_vma=False)
 
     b = cfg.batch
     args = (stacked,

@@ -35,10 +35,11 @@ from repro.core.engine import (BatchedSummarizer, EngineConfig,
                                ShardedSummarizer)
 from repro.core.engine.state import OBJECTIVES, PROPOSALS
 from repro.core.reference import ALGORITHMS, WeightedDynamicSummary
-from repro.dist.router import DEFAULT_REPLICA_EXEC, REPLICA_EXEC_MODES
+from repro.dist.router import REPLICA_EXEC_MODES
 from repro.graph.streams import (barabasi_albert_edges, copying_model_edges,
                                  edges_to_fully_dynamic_stream,
                                  edges_to_insertion_stream)
+from repro.launch.cache import enable_compile_cache
 
 
 def make_stream(kind: str, nodes: int, edges_per_node: int, beta: float,
@@ -84,11 +85,11 @@ def main() -> None:
                          "(measures the pipeline gap; results are "
                          "bit-identical)")
     ap.add_argument("--replica-exec", choices=list(REPLICA_EXEC_MODES),
-                    default=DEFAULT_REPLICA_EXEC,
+                    default=None,
                     help="sharded: lay the per-device shard replicas out "
-                         "as one vmapped program (default) or a "
-                         "serializing lax.map (the differential "
-                         "reference; results are bit-identical)")
+                         "as one vmapped program or a serializing lax.map "
+                         "(results are bit-identical; default: vmap on "
+                         "accelerators, map on the CPU)")
     ap.add_argument("--algo", choices=list(ALGORITHMS), default="mosso")
     ap.add_argument("--graph", choices=["ba", "copying"], default="ba")
     ap.add_argument("--nodes", type=int, default=2000)
@@ -125,6 +126,7 @@ def main() -> None:
                     help="failed chunks tolerated before giving up "
                          "(with --checkpoint-dir)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.checkpoint_dir and args.engine == "reference":
         ap.error("--checkpoint-dir requires --engine batched or sharded "
                  "(the reference tier has no checkpoint closure)")

@@ -244,7 +244,6 @@ def make_sharded_query_kernels(cfg, mesh, trial_backend: str | None = None,
     whose across-shard disjunction is the seen-label contract.  Memoized
     on ``(cfg, mesh, trial_backend)`` like the router steps.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.dist.router import _state_specs
@@ -282,10 +281,10 @@ def make_sharded_query_kernels(cfg, mesh, trial_backend: str | None = None,
             return jax.vmap(per_shard)(est, ist)
 
     def wrap(fn, n_q_args, n_out):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh,
             in_specs=(est_specs, ist_specs) + (P(),) * n_q_args,
-            out_specs=(P(axis),) * n_out, check_rep=False))
+            out_specs=(P(axis),) * n_out, check_vma=False))
 
     kernels = QueryKernels(neighbors=wrap(nbrs_local, 2, 2),
                            degree=wrap(deg_local, 2, 2),

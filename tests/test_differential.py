@@ -121,7 +121,7 @@ def test_predicated_step_matches_reference_batchwise_property(seed, deg):
 def _count_primitives(jaxpr, name: str) -> int:
     """Occurrences of a primitive at any nesting depth (incl. inside
     pallas_call kernel jaxprs, which live in eqn params)."""
-    import jax.core as jc
+    import jax.extend.core as jc
 
     def subjaxprs(val):
         if isinstance(val, jc.ClosedJaxpr):

@@ -29,7 +29,7 @@ def run_py(code: str) -> str:
 def test_sharded_lm_train_step_matches_single_device():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import REGISTRY
         from repro.dist import sharding as shd
         from repro.models import transformer as tfm
@@ -48,7 +48,10 @@ def test_sharded_lm_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(step)(params, opt, toks, toks)
 
         # sharded 2x4 mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        # GSPMD (Auto) axes: jax.make_mesh defaults to Explicit axes, under
+        # which the embedding gather's output spec repeats 'data'
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         p_sh = shd.tree_shardings(params, shd.LM_RULES, mesh)
         o_sh = adamw.AdamWState(step=NamedSharding(mesh, P()),
                                 m=shd.tree_shardings(params, shd.LM_RULES, mesh),
@@ -72,7 +75,6 @@ def test_distributed_mosso_phi_equals_sum_of_shards():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core.engine import BatchedSummarizer, EngineConfig
         from repro.core.engine.state import new_state
         from repro.core.engine.trial import step_fn
@@ -100,11 +102,11 @@ def test_distributed_mosso_phi_equals_sum_of_shards():
         st1 = new_state(cfg)
         stacked = jax.tree.map(
             lambda l: jnp.broadcast_to(l[None], (n_dev,) + l.shape), st1)
-        dist = jax.jit(shard_map(
+        dist = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("d"), st1), P("d"), P("d"), P("d")),
             out_specs=(jax.tree.map(lambda _: P("d"), st1), P("d")),
-            check_rep=False))
+            check_vma=False))
 
         b = cfg.batch
         n_steps = max(len(s) for s in shards)
@@ -327,7 +329,6 @@ def test_data_parallel_wrapper_and_cache():
 def test_compressed_psum_error_bounded():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import compressed_psum, int8_quantize, int8_dequantize
 
@@ -337,8 +338,8 @@ def test_compressed_psum_error_bounded():
         assert err <= float(s) * 0.51 + 1e-6
 
         mesh = jax.make_mesh((8,), ("d",))
-        f = shard_map(lambda a: compressed_psum(a, "d"), mesh=mesh,
-                      in_specs=P("d"), out_specs=P(), check_rep=False)
+        f = jax.shard_map(lambda a: compressed_psum(a, "d"), mesh=mesh,
+                          in_specs=P("d"), out_specs=P(), check_vma=False)
         got = f(x)
         want = jnp.sum(x, axis=0)
         rel = float(jnp.max(jnp.abs(got - want)) / (jnp.max(jnp.abs(want)) + 1e-9))

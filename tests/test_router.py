@@ -513,16 +513,35 @@ def test_replica_exec_default_is_backend_aware_and_validated():
     mode must fail fast."""
     import jax
 
-    from repro.dist.router import DEFAULT_REPLICA_EXEC, REPLICA_EXEC_MODES
+    from repro.dist.router import REPLICA_EXEC_MODES, default_replica_exec
 
-    assert DEFAULT_REPLICA_EXEC in REPLICA_EXEC_MODES
+    assert default_replica_exec() in REPLICA_EXEC_MODES
     if jax.default_backend() == "cpu" and "REPRO_REPLICA_EXEC" not in \
             __import__("os").environ:
-        assert DEFAULT_REPLICA_EXEC == "map"
+        assert default_replica_exec() == "map"
     ss = ShardedSummarizer(_cfg(), n_shards=2, router_chunk=64)
-    assert ss.replica_exec == DEFAULT_REPLICA_EXEC
+    assert ss.replica_exec == default_replica_exec()
     with pytest.raises(ValueError, match="replica_exec"):
         ShardedSummarizer(_cfg(), n_shards=2, replica_exec="pmap")
+
+
+def test_importing_the_main_path_starts_no_backend():
+    """The replica layout, the probe backend and the platform are chosen
+    when a summarizer is built, so importing the package must not start a
+    JAX backend (an entry point may still have to pick or refuse one)."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro.core.engine.api, repro.dist.router, "
+            "repro.serve.query, repro.checkpoint.summary, "
+            "repro.launch.stream\n"
+            "from jax._src import xla_bridge\n"
+            "print(xla_bridge.backends_are_initialized())")
+    out = subprocess.run([sys.executable, "-c", code], env=dict(os.environ),
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() == "False"
 
 
 def test_label_buffer_compacts_on_long_zero_sync_runs():
