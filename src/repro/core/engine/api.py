@@ -20,6 +20,7 @@ from repro.core.engine.state import EngineConfig, EngineState, new_state
 from repro.core.engine.trial import make_step
 from repro.core.summary import (ShardedSummaryOutput, SummaryOutput,
                                 encoding_cost, is_superedge, pair_key)
+from repro.obs import SpanRecorder
 
 Change = Tuple[int, int, bool]
 
@@ -558,7 +559,16 @@ class ShardedSummarizer(_CrashConsistency):
     beyond the first (carried in the engine stage's device-side state —
     the route stage's round count rides into the engine step, which
     accumulates it on device; fetched only at sync points, with zero
-    host-side buffering of per-chunk counts).
+    host-side buffering of per-chunk counts); beside it
+    ``stats()['engine_rounds']`` counts the engine rounds run, each
+    paying ``n_shards x batch`` slots whether filled or not.
+
+    **Spans.** ``obs`` (:class:`repro.obs.SpanRecorder`) records where the
+    host's time goes: ``summarizer.process`` per call with its
+    ``journal``/``pack``/``route``/``engine``/``compact_labels`` children
+    (request id: the chunk's journal sequence number), ``summarizer.sync``
+    at sync points, and the query views' ``query.*`` spans.  Recording
+    fetches nothing from the device; ``obs.enabled = False`` turns it off.
 
     **Capacity semantics.** Edge partitioning is a vertex cut: a node
     touching edges in several partitions occupies a local id in each, so
@@ -635,9 +645,12 @@ class ShardedSummarizer(_CrashConsistency):
         self.router_overflows = 0   # changes spilled to the host path
         self.router_syncs = 0       # per-chunk watermark fetches performed
         self.chunk_sync = bool(chunk_sync)
-        # drain-round telemetry lives IN the engine stage's carried state
-        # (int32[n_dev], accumulated on device, fetched only at sync points)
+        # drain- and engine-round telemetry lives IN the engine stage's
+        # carried state (int32[n_dev, 2], accumulated on device, fetched
+        # only at sync points); host-path rounds are counted on the host
         self._drain_rounds = dist_router.drain_telemetry_new(n_dev)
+        self._host_engine_rounds = 0
+        self.obs = SpanRecorder()   # in-program spans (repro.obs)
         self._bucketed = dist_router.make_bucketed_step(
             cfg, mesh, replica_exec, self.trial_backend)
         if routing == "device":
@@ -658,11 +671,17 @@ class ShardedSummarizer(_CrashConsistency):
             self.router_geometry = None
             self.max_drain_rounds = None
             self.sync_free = False
+        # the memoized jitted programs per stage, for stats()'s program
+        # counts (the attributes above may be wrapped by a caller)
+        self._stage_fns = {
+            "route": () if self._route is None else (self._route,),
+            "engine": (self._bucketed if self._engine is None
+                       else self._engine,)}
         # the route stage has no state dependencies, so on the sync-free
         # path chunk k+1's routing is dispatched while chunk k's engine
         # rounds execute (one routed chunk in flight, flushed at sync)
         self.pipeline = bool(pipeline) and self.sync_free
-        self._pending = None        # routed buckets awaiting engine dispatch
+        self._pending = None        # (seq, routed buckets) awaiting engine
         self._epoch = 0             # engine dispatches applied to self.state
         self._init_crash_consistency(checkpoint_dir)
 
@@ -844,16 +863,20 @@ class ShardedSummarizer(_CrashConsistency):
         the route/engine pipeline); every state accessor flushes first.
         """
         changes = list(changes)
+        obs = self.obs
         self._in_dispatch = True
         try:
-            for off in range(0, len(changes), self.router_chunk):
-                chunk = changes[off:off + self.router_chunk]
-                self._journal_chunk(chunk)      # durable BEFORE dispatch
-                if self.routing == "device":
-                    self._process_chunk_device(chunk)
-                else:
-                    self._process_chunk_host(chunk)
-                self._cursor += len(chunk)
+            with obs.span("summarizer.process", self._journal_seq):
+                for off in range(0, len(changes), self.router_chunk):
+                    chunk = changes[off:off + self.router_chunk]
+                    seq = self._journal_seq
+                    with obs.span("summarizer.journal", seq):
+                        self._journal_chunk(chunk)  # durable BEFORE dispatch
+                    if self.routing == "device":
+                        self._process_chunk_device(chunk, seq)
+                    else:
+                        self._process_chunk_host(chunk, seq)
+                    self._cursor += len(chunk)
         finally:
             self._in_dispatch = False
 
@@ -862,7 +885,8 @@ class ShardedSummarizer(_CrashConsistency):
         """Stream slice size per journaled dispatch (= ``router_chunk``)."""
         return self.router_chunk
 
-    def _process_chunk_host(self, chunk: Sequence[Change]) -> None:
+    def _process_chunk_host(self, chunk: Sequence[Change],
+                            seq: Optional[int] = None) -> None:
         """Host routing: bucket hashed changes per shard, feed padded
         rounds.  Vectorized (stable ``flatnonzero`` order == stream
         order); shares the packing/hashing path with the device router so
@@ -870,33 +894,39 @@ class ShardedSummarizer(_CrashConsistency):
         from repro.dist import labelhash
 
         self._flush_dispatch()
+        obs = self.obs
         n, b = self.n_shards, self.cfg.batch
-        uh, ul, vh, vl, fl = self._pack_chunk(chunk)
-        dest = np.minimum(labelhash.combine(uh, ul),
-                          labelhash.combine(vh, vl)) % n
-        idxs = [np.flatnonzero(dest == s) for s in range(n)]
+        with obs.span("summarizer.pack", seq):
+            uh, ul, vh, vl, fl = self._pack_chunk(chunk)
+            dest = np.minimum(labelhash.combine(uh, ul),
+                              labelhash.combine(vh, vl)) % n
+            idxs = [np.flatnonzero(dest == s) for s in range(n)]
         rounds = (max((len(i) for i in idxs), default=0) + b - 1) // b
-        for r in range(rounds):
-            buh = np.full((n, b), -1, np.int32)
-            bul = np.full((n, b), -1, np.int32)
-            bvh = np.full((n, b), -1, np.int32)
-            bvl = np.full((n, b), -1, np.int32)
-            bfl = np.zeros((n, b), np.int32)
-            for s, idx in enumerate(idxs):
-                sel = idx[r * b:(r + 1) * b]
-                k = len(sel)
-                if k:
-                    buh[s, :k], bul[s, :k] = uh[sel], ul[sel]
-                    bvh[s, :k], bvl[s, :k] = vh[sel], vl[sel]
-                    bfl[s, :k] = fl[sel]
-            self.state, self.intern = self._bucketed(
-                self.state, self.intern, buh, bul, bvh, bvl, bfl)
+        with obs.span("summarizer.engine", seq):
+            for r in range(rounds):
+                buh = np.full((n, b), -1, np.int32)
+                bul = np.full((n, b), -1, np.int32)
+                bvh = np.full((n, b), -1, np.int32)
+                bvl = np.full((n, b), -1, np.int32)
+                bfl = np.zeros((n, b), np.int32)
+                for s, idx in enumerate(idxs):
+                    sel = idx[r * b:(r + 1) * b]
+                    k = len(sel)
+                    if k:
+                        buh[s, :k], bul[s, :k] = uh[sel], ul[sel]
+                        bvh[s, :k], bvl[s, :k] = vh[sel], vl[sel]
+                        bfl[s, :k] = fl[sel]
+                self.state, self.intern = self._bucketed(
+                    self.state, self.intern, buh, bul, bvh, bvl, bfl)
+        self._host_engine_rounds += rounds
         self._epoch += 1
         self._host_cache = None
         if len(self._label_buf) >= 128:
-            self._compact_label_buf()
+            with obs.span("summarizer.compact_labels", seq):
+                self._compact_label_buf()
 
-    def _process_chunk_device(self, chunk: Sequence[Change]) -> None:
+    def _process_chunk_device(self, chunk: Sequence[Change],
+                              seq: Optional[int] = None) -> None:
         """Device routing: route stage + engine stage, software-pipelined.
 
         In the default (``sync_free``) configuration this method performs
@@ -913,8 +943,11 @@ class ShardedSummarizer(_CrashConsistency):
         gating the host-path replay of an undelivered suffix so stream
         order — and therefore losslessness — is preserved (serial
         dispatch: the pipeline needs the delivery guarantee)."""
-        packed = self._pack_chunk(chunk, pad_to=self.router_chunk)
-        *buckets, counts, delivered, rounds = self._route(*packed)
+        obs = self.obs
+        with obs.span("summarizer.pack", seq):
+            packed = self._pack_chunk(chunk, pad_to=self.router_chunk)
+        with obs.span("summarizer.route", seq):
+            *buckets, counts, delivered, rounds = self._route(*packed)
         # the route stage's round count rides into the engine stage, which
         # folds it into the carried device-side telemetry — no host-side
         # buffering of per-chunk drain counts at all
@@ -923,24 +956,21 @@ class ShardedSummarizer(_CrashConsistency):
         # the label buffer compacts to unique hashes every 128 entries
         # (numpy only: no device fetch, no host dict ops)
         if len(self._label_buf) >= 128:
-            self._compact_label_buf()
+            with obs.span("summarizer.compact_labels", seq):
+                self._compact_label_buf()
         if self.pipeline:
-            prev, self._pending = self._pending, routed
+            prev, self._pending = self._pending, (seq, routed)
             if prev is not None:
-                self.state, self.intern, self._drain_rounds = self._engine(
-                    self.state, self.intern, self._drain_rounds, *prev)
-                self._epoch += 1
+                self._dispatch_engine(*prev)
             return
-        self.state, self.intern, self._drain_rounds = self._engine(
-            self.state, self.intern, self._drain_rounds, *routed)
-        self._epoch += 1
+        self._dispatch_engine(seq, routed)
         if self.sync_free:
             return                           # statically fully delivered
         self.router_syncs += 1
         i0 = int(np.asarray(delivered).min())  # per-chunk sync (fallback gate)
         if i0 < len(chunk):
             self.router_overflows += len(chunk) - i0
-            self._process_chunk_host(chunk[i0:])
+            self._process_chunk_host(chunk[i0:], seq)
 
     def _flush_dispatch(self) -> None:
         """Dispatch the engine stage for a still-pending routed chunk.
@@ -949,9 +979,14 @@ class ShardedSummarizer(_CrashConsistency):
         holds; sync points call this before reading any state."""
         if self._pending is not None:
             prev, self._pending = self._pending, None
+            self._dispatch_engine(*prev)
+
+    def _dispatch_engine(self, seq: Optional[int], routed: tuple) -> None:
+        """Dispatch the engine stage for one routed chunk (no fetch)."""
+        with self.obs.span("summarizer.engine", seq):
             self.state, self.intern, self._drain_rounds = self._engine(
-                self.state, self.intern, self._drain_rounds, *prev)
-            self._epoch += 1
+                self.state, self.intern, self._drain_rounds, *routed)
+        self._epoch += 1
 
     def flush(self) -> None:
         """Public barrier: drain the dispatch pipeline (device-side only).
@@ -999,7 +1034,8 @@ class ShardedSummarizer(_CrashConsistency):
         self._flush_dispatch()
         if self._host_cache is None:
             import jax
-            est, ist = jax.device_get((self.state, self.intern))
+            with self.obs.span("summarizer.sync"):
+                est, ist = jax.device_get((self.state, self.intern))
             self._host_cache = (
                 [jax.tree.map(lambda x: x[s], est)
                  for s in range(self.n_shards)],
@@ -1066,15 +1102,22 @@ class ShardedSummarizer(_CrashConsistency):
         beyond the first (key-skew indicator), ``router_syncs`` counts
         per-chunk watermark fetches (0 when ``sync_free``), and
         ``router_host_dict_ops`` counts label-map mutations inside
-        dispatch (0 on the hash-routed path).  One device transfer
-        (counters only) — this is a sync point."""
+        dispatch (0 on the hash-routed path).  ``engine_rounds`` counts
+        the engine rounds run (each ``n_shards x batch`` slots, filled or
+        not), and ``stage_programs`` the compiled programs each jitted
+        stage holds (``{"route", "engine", "query"}``; ``None`` where the
+        JAX version cannot tell).  One device transfer (counters only) —
+        this is a sync point."""
         import jax
+
+        from repro.dist.router import TELEM_DRAIN, TELEM_ENGINE
         self._flush_dispatch()
         self._fold_labels()
         s = self.state
-        phi, ne, tr, ac, sk, dr, drr = jax.device_get(
-            (s.phi, s.num_edges, s.n_trials, s.n_accept, s.n_skipped,
-             self.intern.n_dropped, self._drain_rounds))
+        with self.obs.span("summarizer.sync"):
+            phi, ne, tr, ac, sk, dr, telem = jax.device_get(
+                (s.phi, s.num_edges, s.n_trials, s.n_accept, s.n_skipped,
+                 self.intern.n_dropped, self._drain_rounds))
         self._raise_if_dropped(int(np.sum(dr)))
         tot = lambda x: int(np.sum(x))  # noqa: E731
         return dict(phi=tot(phi), num_edges=tot(ne),
@@ -1083,9 +1126,13 @@ class ShardedSummarizer(_CrashConsistency):
                     routing=self.routing,
                     router_overflows=self.router_overflows,
                     # engine-stage carried telemetry: every device carries
-                    # the same accumulated count (the drain loop is
-                    # pmin-agreed), so max == the per-run total
-                    router_drain_rounds=int(np.max(drr)),
+                    # the same accumulated counts (the drain loop is
+                    # pmin-agreed, the engine rounds pmax-agreed), so max
+                    # == the per-run total
+                    router_drain_rounds=int(np.max(telem[:, TELEM_DRAIN])),
+                    engine_rounds=(int(np.max(telem[:, TELEM_ENGINE]))
+                                   + self._host_engine_rounds),
+                    stage_programs=self._stage_programs(),
                     router_syncs=self.router_syncs,
                     router_host_dict_ops=self._host_dict_ops,
                     router_sync_free=self.sync_free,
@@ -1095,6 +1142,21 @@ class ShardedSummarizer(_CrashConsistency):
                     # closure or the bitwise-recovery bar (it counts the
                     # recoveries themselves)
                     stream_retries=self.stream_retries)
+
+    def _stage_programs(self) -> dict:
+        """Compiled programs held per jitted stage (``None`` per stage
+        where the JAX version does not expose a jit's cache size)."""
+        from repro.serve.query import make_sharded_query_kernels
+        query = make_sharded_query_kernels(self.cfg, self.mesh,
+                                           self.trial_backend)
+        fns = dict(self._stage_fns, query=tuple(query))
+        out = {}
+        for stage, stage_fns in fns.items():
+            try:
+                out[stage] = sum(int(f._cache_size()) for f in stage_fns)
+            except AttributeError:
+                out[stage] = None
+        return out
 
     # ----------------------------------------------------- recovery closure
     def _ckpt_tree(self) -> dict:
@@ -1111,6 +1173,7 @@ class ShardedSummarizer(_CrashConsistency):
         # the lazy label buffer, so the map alone carries label recovery
         return {"h2label": dict(self.host_label_map()),
                 "drain_rounds": np.asarray(self._drain_rounds),
+                "host_engine_rounds": self._host_engine_rounds,
                 "router_overflows": self.router_overflows,
                 "router_syncs": self.router_syncs,
                 "host_dict_ops": self._host_dict_ops}
@@ -1147,6 +1210,7 @@ class ShardedSummarizer(_CrashConsistency):
         self.intern = dist_router.InternState(**tree["ist"])
         self._drain_rounds = dist_router.drain_telemetry_restore(
             host["drain_rounds"], int(self.mesh.devices.size))
+        self._host_engine_rounds = int(host.get("host_engine_rounds", 0))
         self._h2label = dict(host["h2label"])
         self._label_buf = []
         self._label_head = None

@@ -159,8 +159,9 @@ def _one_trial(st: EngineState, y: jax.Array, tp: jax.Array,
 
     f = jnp.zeros((), bool)
     z32 = jnp.int32(0)
-    esc, a, target, ok, cap_ok = _pregion(pred, plan, (f, z32, z32, f, f),
-                                          dense)
+    with jax.named_scope("plan"):
+        esc, a, target, ok, cap_ok = _pregion(pred, plan,
+                                              (f, z32, z32, f, f), dense)
 
     def eval_phi(c):
         # masked data flow: dphi of the candidate move (a -> a when the
@@ -169,8 +170,8 @@ def _one_trial(st: EngineState, y: jax.Array, tp: jax.Array,
         return objective(st, y, tgt_s, esc, cfg)
 
     c2 = (z32, jnp.full((d_cap,), -1, jnp.int32), jnp.zeros((d_cap,), bool))
-    dphi, nbrs, nvalid = pwhen(ok, eval_phi, c2)
-    commit = ok & accept(dphi, cfg)
+    with jax.named_scope("eval_phi"):
+        dphi, nbrs, nvalid = pwhen(ok, eval_phi, c2)
 
     def commit_tail(st: EngineState) -> EngineState:
         st = alloc_sid(st, ok=commit & esc)[0]
@@ -178,7 +179,9 @@ def _one_trial(st: EngineState, y: jax.Array, tp: jax.Array,
         return st._replace(
             n_accept=st.n_accept + jnp.where(commit, 1, 0).astype(jnp.int32))
 
-    st = pwhen(commit, commit_tail, st)
+    with jax.named_scope("commit"):
+        commit = ok & accept(dphi, cfg)
+        st = pwhen(commit, commit_tail, st)
     return st._replace(
         n_trials=st.n_trials + jnp.where(pred, 1, 0).astype(jnp.int32),
         n_skipped=st.n_skipped
@@ -243,7 +246,8 @@ def step_fn(st: EngineState, u: jax.Array, v: jax.Array, ins: jax.Array,
                              dense), None
 
     changes = jnp.stack([u, v, ins.astype(jnp.int32)], axis=1)
-    st, _ = jax.lax.scan(ap, st, changes)
+    with jax.named_scope("apply"):
+        st, _ = jax.lax.scan(ap, st, changes)
 
     nodes = jnp.stack([u, v], axis=1).reshape(-1)  # u0,v0,u1,v1,...
 
@@ -252,7 +256,9 @@ def step_fn(st: EngineState, u: jax.Array, v: jax.Array, ins: jax.Array,
         seed = rnd_u32(st.step_no, idx.astype(jnp.uint32) * jnp.uint32(2654435761))
         return _trial_group(st, node, seed, cfg, dense), None
 
-    st, _ = jax.lax.scan(tg, st, (nodes, jnp.arange(nodes.shape[0], dtype=jnp.int32)))
+    with jax.named_scope("trial_group"):
+        st, _ = jax.lax.scan(
+            tg, st, (nodes, jnp.arange(nodes.shape[0], dtype=jnp.int32)))
     return st._replace(step_no=st.step_no + jnp.uint32(1))
 
 

@@ -42,7 +42,8 @@ a two-stage software pipeline:
    the replica axis (possible because the trial engine is cond-free
    predicated data flow) or a serializing ``lax.map`` — and the route
    stage's drain-round count is folded into the stage's carried
-   telemetry on device (``telem += rounds - 1``).
+   telemetry on device (``telem += rounds - 1``), next to the count of
+   engine rounds run.
 
 Because stage 1 depends only on the chunk (never on engine or intern
 state), ``ShardedSummarizer`` dispatches chunk k+1's routing — drain
@@ -191,26 +192,42 @@ def intern_new(cfg: EngineConfig) -> InternState:
     )
 
 
+# columns of the engine stage's carried telemetry
+TELEM_DRAIN = 0     # extra exchange rounds beyond the first, per chunk
+TELEM_ENGINE = 1    # engine rounds run (pmax-agreed ceil(max_count / batch))
+
+
 def drain_telemetry_new(n_dev: int) -> jax.Array:
-    """Fresh engine-stage drain-round telemetry carry (``int32[n_dev]``).
+    """Fresh engine-stage telemetry carry (``int32[n_dev, 2]``): per
+    device the drain-round count (column ``TELEM_DRAIN``) and the
+    engine-round count (column ``TELEM_ENGINE``).
 
     Crash-consistency note (``repro.checkpoint.summary``): the route stage
     is a pure function of the chunk — it has no state to checkpoint.  The
     recovery closure is exactly the engine stage's carried operands: the
     stacked ``EngineState`` + :class:`InternState` and this telemetry
-    vector.  The drain loop is pmin/pmax-agreed, so the vector is
-    mesh-uniform by construction; a checkpoint can therefore restore it
-    onto a mesh with a *different* device count by broadcasting the
-    per-run count (``max``) — the basis of the elastic-restore leg.
+    array.  The drain loop is pmin-agreed and the engine rounds
+    pmax-agreed, so every row is the same by construction; a checkpoint
+    can therefore restore it onto a mesh with a *different* device count
+    by broadcasting the per-run counts (``max`` per column) — the basis of
+    the elastic-restore leg.
     """
-    return jnp.zeros((n_dev,), jnp.int32)
+    return jnp.zeros((n_dev, 2), jnp.int32)
 
 
 def drain_telemetry_restore(saved, n_dev: int) -> jax.Array:
-    """Re-broadcast a saved (mesh-uniform) drain-round vector onto a mesh
-    of ``n_dev`` devices; bitwise-identical when the topology matches."""
-    count = jnp.int32(np.max(np.asarray(saved))) if np.size(saved) else 0
-    return jnp.full((n_dev,), count, jnp.int32)
+    """Re-broadcast saved (mesh-uniform) telemetry onto a mesh of ``n_dev``
+    devices; bitwise-identical when the topology matches.  A checkpoint
+    written before the engine-round column (``int32[n_dev]``) restores
+    with that count at 0."""
+    saved = np.asarray(saved, np.int32)
+    if saved.size == 0:
+        counts = np.zeros(2, np.int32)
+    elif saved.ndim == 1:
+        counts = np.array([saved.max(), 0], np.int32)
+    else:
+        counts = saved.max(axis=0)
+    return jnp.asarray(np.tile(counts, (n_dev, 1)))
 
 
 def _intern_probe(ist: InternState, hi: jax.Array, lo: jax.Array,
@@ -525,12 +542,14 @@ def make_route_step(mesh, n_shards: int, chunk: int, lane_cap: int,
 
     def local(uh, ul, vh, vl, ins):
         # uh/ul/vh/vl/ins local [n_in]
-        me = jax.lax.axis_index(axis)
-        valid = (uh >= 0) & (vh >= 0)
-        dest = jnp.where(valid, shard_key(uh, ul, vh, vl, n_shards), n_shards)
-        pos = me * n_in + jnp.arange(n_in, dtype=jnp.int32)
-        payload = jnp.stack(
-            [uh, ul, vh, vl, ins.astype(jnp.int32)], axis=-1)
+        with jax.named_scope("route/keys"):
+            me = jax.lax.axis_index(axis)
+            valid = (uh >= 0) & (vh >= 0)
+            dest = jnp.where(valid, shard_key(uh, ul, vh, vl, n_shards),
+                             n_shards)
+            pos = me * n_in + jnp.arange(n_in, dtype=jnp.int32)
+            payload = jnp.stack(
+                [uh, ul, vh, vl, ins.astype(jnp.int32)], axis=-1)
         rows = jnp.arange(n_loc, dtype=jnp.int32)[:, None]
         sid = jnp.arange(n_shards, dtype=jnp.int32)[None]
 
@@ -583,9 +602,10 @@ def make_route_step(mesh, n_shards: int, chunk: int, lane_cap: int,
         init = (jnp.int32(0), jnp.int32(0),
                 jnp.full((n_loc, acc_cap, 5), -1, jnp.int32),
                 jnp.zeros((n_loc,), jnp.int32))
-        rounds, delivered, acc, counts = jax.lax.while_loop(
-            lambda c: (c[1] < chunk) & (c[0] < geom.max_drain_rounds),
-            drain_round, init)
+        with jax.named_scope("route/drain"):
+            rounds, delivered, acc, counts = jax.lax.while_loop(
+                lambda c: (c[1] < chunk) & (c[0] < geom.max_drain_rounds),
+                drain_round, init)
         return (acc[..., 0], acc[..., 1], acc[..., 2], acc[..., 3],
                 acc[..., 4], counts, delivered[None], rounds[None])
 
@@ -616,10 +636,11 @@ def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
     axis (default), or a serializing ``lax.map`` (the differential
     reference).
 
-    ``telem`` is the carried routing telemetry (``int32[n_dev]``, equal
-    across devices): the stage folds the route stage's drain-round count
-    ``rounds`` into it on device (``telem += rounds - 1``), so the host
-    never buffers per-chunk round counts.  The engine/intern/telemetry
+    ``telem`` is the carried telemetry (``int32[n_dev, 2]``, equal across
+    devices; :func:`drain_telemetry_new`): the stage folds the route
+    stage's drain-round count ``rounds`` into it on device
+    (``+= rounds - 1``) and, beside it, the engine rounds it ran, so the
+    host never buffers per-chunk round counts.  The engine/intern/telemetry
     states AND the bucket buffers are donated on non-CPU backends — the
     buckets are the pipeline's double buffer, consumed exactly once.
 
@@ -648,15 +669,17 @@ def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
     def _local(est, ist, telem, a_uh, a_ul, a_vh, a_vl, a_ins, counts,
                rounds):
         # est/ist stacked [n_loc, ...]; buckets [n_loc, acc_cap];
-        # telem/rounds [1] (device-local slice of the [n_dev] array)
+        # telem [1, 2], rounds [1] (device-local slices of the [n_dev]
+        # arrays)
         # intern each shard's whole bucket up front — the same order host
         # bucketing interns in, so both paths assign identical local ids
         def int_one(ist_l, uh_l, ul_l, vh_l, vl_l):
             return intern_changes(ist_l, uh_l, ul_l, vh_l, vl_l,
                                   cfg.n_cap, dense)
 
-        ist, u_all, v_all = _replica_apply(
-            int_one, replica_exec, ist, a_uh, a_ul, a_vh, a_vl)
+        with jax.named_scope("engine/intern"):
+            ist, u_all, v_all = _replica_apply(
+                int_one, replica_exec, ist, a_uh, a_ul, a_vh, a_vl)
 
         # one spare round of padding so dynamic_slice never clamps
         u_all = jnp.concatenate(
@@ -682,11 +705,14 @@ def make_engine_step(cfg: EngineConfig, mesh, n_shards: int, acc_cap: int,
             return r + 1, _replica_apply(one, replica_exec,
                                          est, u_all, v_all, i_all)
 
-        _, est = jax.lax.while_loop(
-            lambda c: c[0] < erounds, round_body, (jnp.int32(0), est))
-        # drain-round telemetry: extra exchange rounds beyond the first,
-        # accumulated device-side (rounds is mesh-uniform by construction)
-        return est, ist, telem + rounds - 1
+        with jax.named_scope("engine/round"):
+            _, est = jax.lax.while_loop(
+                lambda c: c[0] < erounds, round_body, (jnp.int32(0), est))
+        # telemetry, accumulated device-side: extra exchange rounds beyond
+        # the first, and the engine rounds run (both mesh-uniform by
+        # construction)
+        return est, ist, telem + jnp.concatenate([rounds - 1,
+                                                  erounds[None]])[None]
 
     fn = jax.jit(jax.shard_map(
         local, mesh=mesh,

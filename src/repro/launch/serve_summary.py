@@ -41,7 +41,10 @@ def serve_summary(summarizer: ShardedSummarizer, stream: Sequence,
     fresh ``query()`` snapshot after every chunk — while the pipelined
     router still has that chunk's engine stage (and the next chunk's
     routing) in flight.  With ``verify`` each read batch is compared to
-    the edge set of the snapshot's epoch prefix.
+    the edge set of the snapshot's epoch prefix.  ``read_ms_p50`` and
+    ``read_ms_p95`` are taken from the program's own read spans (one
+    ``query.*`` root span per read batch; ``None`` with the summarizer's
+    span recorder off).
     """
     rng = np.random.default_rng(seed)
     chunk_n = summarizer.router_chunk
@@ -52,7 +55,7 @@ def serve_summary(summarizer: ShardedSummarizer, stream: Sequence,
     live: set = set()
 
     n_reads = 0
-    t_read = 0.0
+    t_start = time.perf_counter()
     lags: list = []
     for k in range(n_chunks):
         chunk = stream[k * chunk_n:(k + 1) * chunk_n]
@@ -77,12 +80,10 @@ def serve_summary(summarizer: ShardedSummarizer, stream: Sequence,
         labs = [pool[i] for i in
                 rng.integers(0, len(pool), reads_per_chunk)]
         pairs = list(zip(labs, labs[::-1]))
-        t0 = time.perf_counter()
         nbrs = view.neighbors_batch(labs)
         degs = view.degree_batch(labs)
         present = [view.has_edge(u, v) if u != v else False
                    for (u, v) in pairs[:8]]
-        t_read += time.perf_counter() - t0
         n_reads += len(labs) * 2 + len(present)
 
         if verify:
@@ -99,6 +100,9 @@ def serve_summary(summarizer: ShardedSummarizer, stream: Sequence,
                 want = (min(u, v), max(u, v)) in truth
                 assert p == want, f"epoch {view.epoch} has_edge({u!r},{v!r})"
 
+    # one root span per read batch, in ms
+    reads = [s.seconds * 1e3 for s in summarizer.obs.spans(
+        "query.", t_start, time.perf_counter()) if s.parent_id == 0]
     summarizer.flush()
     final = summarizer.query()
     assert final.epoch == n_chunks
@@ -113,7 +117,8 @@ def serve_summary(summarizer: ShardedSummarizer, stream: Sequence,
             assert s == adj.get(lab, set()), f"final neighbors({lab!r})"
 
     return dict(chunks=n_chunks, changes=len(stream), reads=n_reads,
-                us_per_read=1e6 * t_read / max(n_reads, 1),
+                read_ms_p50=float(np.percentile(reads, 50)) if reads else None,
+                read_ms_p95=float(np.percentile(reads, 95)) if reads else None,
                 epoch_lags=lags, max_lag=max(lags, default=0),
                 reads_overlapped_writes=any(l > 0 for l in lags),
                 final_epoch=final.epoch, phi=summarizer.phi,
@@ -163,8 +168,9 @@ def main() -> None:
                         verify=args.verify, seed=args.seed)
     el = time.time() - t0
     print(f"served {out['reads']} reads over {out['chunks']} write chunks "
-          f"({out['us_per_read']:.0f} us/read, max epoch lag "
-          f"{out['max_lag']}, overlapped={out['reads_overlapped_writes']})")
+          f"(read batch ms p50 {out['read_ms_p50']} p95 "
+          f"{out['read_ms_p95']}, max epoch lag {out['max_lag']}, "
+          f"overlapped={out['reads_overlapped_writes']})")
     print(f"phi={out['phi']} |E|={out['num_edges']} "
           f"verified={out['verified']}  total {el:.1f}s "
           f"({1e6 * el / len(stream):.0f} us/change incl. reads)")

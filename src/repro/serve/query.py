@@ -258,25 +258,31 @@ def make_sharded_query_kernels(cfg, mesh, trial_backend: str | None = None,
     def nbrs_local(est, ist, hi, lo):
         with trial_backend_scope(trial_backend):
             def per_shard(st, it):
-                nid, found = _intern_resolve(it, hi, lo)
-                mask, _ = jax.vmap(lambda x: _neighbors_one(st, x))(nid)
+                with jax.named_scope("query/resolve"):
+                    nid, found = _intern_resolve(it, hi, lo)
+                with jax.named_scope("query/scan"):
+                    mask, _ = jax.vmap(lambda x: _neighbors_one(st, x))(nid)
                 return mask, found
             return jax.vmap(per_shard)(est, ist)
 
     def deg_local(est, ist, hi, lo):
         with trial_backend_scope(trial_backend):
             def per_shard(st, it):
-                nid, found = _intern_resolve(it, hi, lo)
-                d, _ = _degree_core(st, nid)
+                with jax.named_scope("query/resolve"):
+                    nid, found = _intern_resolve(it, hi, lo)
+                with jax.named_scope("query/scan"):
+                    d, _ = _degree_core(st, nid)
                 return d, found
             return jax.vmap(per_shard)(est, ist)
 
     def he_local(est, ist, uhi, ulo, vhi, vlo):
         with trial_backend_scope(trial_backend):
             def per_shard(st, it):
-                nu, fu = _intern_resolve(it, uhi, ulo)
-                nv, fv = _intern_resolve(it, vhi, vlo)
-                present, se, _ = _has_edge_core(st, nu, nv)
+                with jax.named_scope("query/resolve"):
+                    nu, fu = _intern_resolve(it, uhi, ulo)
+                    nv, fv = _intern_resolve(it, vhi, vlo)
+                with jax.named_scope("query/scan"):
+                    present, se, _ = _has_edge_core(st, nu, nv)
                 return present, se, fu, fv
             return jax.vmap(per_shard)(est, ist)
 
@@ -410,6 +416,8 @@ class ShardedSummaryQuery:
         self.epoch = summarizer.flush_epoch
         self.n_shards = summarizer.n_shards
         self._inc = summarizer._incarnation  # restore fences this view
+        self._obs = summarizer.obs
+        self._batches = 0           # read batches served: span request ids
 
     # ------------------------------------------------------------- id space
     def _check_pin(self) -> None:
@@ -436,6 +444,11 @@ class ShardedSummaryQuery:
                 raise LookupError(
                     f"query: label {lab!r} has not been streamed "
                     f"(as of epoch {self.epoch})")
+
+    def _batch_span(self, name: str):
+        """Root span of one read batch, request id ``(epoch, batch)``."""
+        self._batches += 1
+        return self._obs.span(name, (self.epoch, self._batches))
 
     def _snapshot_intern(self):
         """Host copy of the snapshot's reverse maps (one fetch, memoized);
@@ -473,33 +486,46 @@ class ShardedSummaryQuery:
 
     # -------------------------------------------------------------- queries
     def neighbors_batch(self, labels: Sequence[object]) -> List[Set[object]]:
-        hi, lo = self._hash_words(labels)
-        mask, found = self._k.neighbors(self._est, self._ist, hi, lo)
-        mask, found = np.asarray(mask), np.asarray(found)
-        self._snapshot_intern()
-        self._require_seen(labels, found)
-        out: List[Set[object]] = []
-        for q in range(len(labels)):
-            acc: Set[object] = set()
-            for s in range(self.n_shards):
-                hits = np.flatnonzero(mask[s, q])
-                if hits.size:
-                    rev = self._rev(s)
-                    acc.update(rev[int(w)] for w in hits)
-            out.append(acc)
+        obs = self._obs
+        with self._batch_span("query.neighbors"):
+            with obs.span("query.hash"):
+                hi, lo = self._hash_words(labels)
+            with obs.span("query.dispatch"):
+                mask, found = self._k.neighbors(self._est, self._ist, hi, lo)
+            with obs.span("query.wait"):        # blocked on the device
+                mask, found = np.asarray(mask), np.asarray(found)
+                self._snapshot_intern()
+            with obs.span("query.decode"):
+                self._require_seen(labels, found)
+                out: List[Set[object]] = []
+                for q in range(len(labels)):
+                    acc: Set[object] = set()
+                    for s in range(self.n_shards):
+                        hits = np.flatnonzero(mask[s, q])
+                        if hits.size:
+                            rev = self._rev(s)
+                            acc.update(rev[int(w)] for w in hits)
+                    out.append(acc)
         return out
 
     def neighbors(self, label: object) -> Set[object]:
         return self.neighbors_batch([label])[0]
 
     def degree_batch(self, labels: Sequence[object]) -> List[int]:
-        hi, lo = self._hash_words(labels)
-        d, found = self._k.degree(self._est, self._ist, hi, lo)
-        d, found = np.asarray(d), np.asarray(found)
-        self._snapshot_intern()
-        self._require_seen(labels, found)
-        # per-shard edge partitions are disjoint, so degrees add exactly
-        return [int(x) for x in d.sum(axis=0)[:len(labels)]]
+        obs = self._obs
+        with self._batch_span("query.degree"):
+            with obs.span("query.hash"):
+                hi, lo = self._hash_words(labels)
+            with obs.span("query.dispatch"):
+                d, found = self._k.degree(self._est, self._ist, hi, lo)
+            with obs.span("query.wait"):
+                d, found = np.asarray(d), np.asarray(found)
+                self._snapshot_intern()
+            with obs.span("query.decode"):
+                self._require_seen(labels, found)
+                # per-shard edge partitions are disjoint, so degrees add
+                # exactly
+                return [int(x) for x in d.sum(axis=0)[:len(labels)]]
 
     def degree(self, label: object) -> int:
         return self.degree_batch([label])[0]
@@ -508,20 +534,30 @@ class ShardedSummaryQuery:
                           ) -> np.ndarray:
         """bool[n_shards, len(pairs)]: which shard holds each edge.  At
         most one True per column — the pair's ``shard_key`` owner."""
-        uh, ul = self._hash_words([p[0] for p in pairs])
-        vh, vl = self._hash_words([p[1] for p in pairs])
-        present, _, fu, fv = self._k.has_edge(
-            self._est, self._ist, uh, ul, vh, vl)
-        present, fu, fv = (np.asarray(x) for x in (present, fu, fv))
-        self._snapshot_intern()
-        self._require_seen([p[0] for p in pairs], fu)
-        self._require_seen([p[1] for p in pairs], fv)
-        return present[:, :len(pairs)]
+        with self._batch_span("query.has_edge"):
+            return self._has_edge_by_shard(pairs)
+
+    def _has_edge_by_shard(self, pairs) -> np.ndarray:
+        obs = self._obs
+        with obs.span("query.hash"):
+            uh, ul = self._hash_words([p[0] for p in pairs])
+            vh, vl = self._hash_words([p[1] for p in pairs])
+        with obs.span("query.dispatch"):
+            present, _, fu, fv = self._k.has_edge(
+                self._est, self._ist, uh, ul, vh, vl)
+        with obs.span("query.wait"):
+            present, fu, fv = (np.asarray(x) for x in (present, fu, fv))
+            self._snapshot_intern()
+        with obs.span("query.decode"):
+            self._require_seen([p[0] for p in pairs], fu)
+            self._require_seen([p[1] for p in pairs], fv)
+            return present[:, :len(pairs)]
 
     def has_edge_batch(self, pairs: Sequence[Tuple[object, object]],
                        ) -> List[bool]:
-        present = self.has_edge_by_shard(pairs)
-        return [bool(x) for x in present.any(axis=0)]
+        with self._batch_span("query.has_edge"):
+            present = self._has_edge_by_shard(pairs)
+            return [bool(x) for x in present.any(axis=0)]
 
     def has_edge(self, u: object, v: object) -> bool:
         return self.has_edge_batch([(u, v)])[0]

@@ -170,6 +170,61 @@ def test_query_answers_survive_mid_stream_recovery(tmp_path):
     assert got == want
 
 
+@pytest.mark.parametrize("routing", ["device", "host"])
+def test_engine_round_counter_recovers_bitwise(tmp_path, routing):
+    """The engine-round count rides in the recovery closure (device
+    telemetry, or the host count on the host path): a run killed
+    mid-stream and recovered counts exactly the rounds of the
+    uninterrupted run, at the kill point and at the end."""
+    stream = _stream(160)
+    ref = _sharded(routing=routing)
+    snaps = _snapshots(ref, stream)
+    want = ref.stats()["engine_rounds"]
+    assert want >= len(snaps)
+    prefix = _sharded(routing=routing)
+    inject.drive(prefix, stream[:3 * 32])
+    d = str(tmp_path)
+    crashed = _sharded(d, routing=routing)
+    with pytest.raises(inject.SimulatedCrash):
+        inject.drive(crashed, stream, ckpt_every=2, kill_at_chunk=3)
+    rec = _sharded(d, routing=routing)
+    rec.recover()
+    rec.flush()
+    assert rec.stats()["engine_rounds"] == prefix.stats()["engine_rounds"]
+    np.testing.assert_array_equal(rec._ckpt_host()["drain_rounds"],
+                                  snaps[2][1]["drain_rounds"])
+    inject.drive(rec, stream, start=rec.stream_cursor)
+    rec.flush()
+    assert rec.stats()["engine_rounds"] == want
+    assert_leaves_equal(rec._ckpt_tree(), ref._ckpt_tree())
+    host = rec._ckpt_host()
+    np.testing.assert_array_equal(host["drain_rounds"],
+                                  snaps[-1][1]["drain_rounds"])
+    assert host["host_engine_rounds"] == snaps[-1][1]["host_engine_rounds"]
+
+
+def test_closure_without_engine_round_column_restores_it_at_zero():
+    """A recovery closure written before the engine-round counter (drain
+    telemetry ``int32[n_dev]``, no host count) restores with the count at
+    0 and every other leaf and counter unchanged."""
+    stream = _stream(96)
+    ref = _sharded()
+    inject.drive(ref, stream)
+    ref.flush()
+    old_host = dict(ref._ckpt_host())
+    old_host["drain_rounds"] = old_host["drain_rounds"][:, 0]
+    del old_host["host_engine_rounds"]
+    extra = {"epoch": ref.flush_epoch, "journal_seq": ref._journal_seq,
+             "cursor": ref.stream_cursor}
+    fresh = _sharded()
+    fresh._ckpt_apply(ref._ckpt_tree(), old_host, extra)
+    assert_leaves_equal(fresh._ckpt_tree(), ref._ckpt_tree())
+    got, want = fresh.stats(), ref.stats()
+    assert want["engine_rounds"] > 0 and got["engine_rounds"] == 0
+    got.pop("engine_rounds"), want.pop("engine_rounds")
+    assert got == want
+
+
 # --------------------------------------------------------------------------- #
 # checkpoint faults
 # --------------------------------------------------------------------------- #

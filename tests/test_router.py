@@ -561,3 +561,202 @@ def test_label_buffer_compacts_on_long_zero_sync_runs():
     assert st["router_syncs"] == 0 and st["router_host_dict_ops"] == 0
     assert ss.live_edges() == ground_truth_edges(stream)
     assert ss.materialize().decode_edges() == ground_truth_edges(stream)
+
+
+# --------------------------------------------------------------------------- #
+# engine-round counter, in-program spans and named scopes
+# --------------------------------------------------------------------------- #
+
+
+def _host_round_schedule(stream, n_shards, chunk, batch):
+    """The engine rounds host bucketing runs: per chunk,
+    ``ceil(max shard load / batch)``, computed from the label hashes."""
+    from repro.dist.labelhash import hash_label
+
+    total = 0
+    for off in range(0, len(stream), chunk):
+        keys = [min(hash_label(u), hash_label(v)) % n_shards
+                for (u, v, _) in stream[off:off + chunk]]
+        load = np.bincount(keys, minlength=n_shards)
+        total += -(-int(load.max()) // batch)
+    return total
+
+
+@pytest.mark.parametrize("routing", ["device", "host"])
+@pytest.mark.parametrize("skewed", [False, True])
+def test_engine_rounds_match_the_host_schedule(routing, skewed):
+    """``stats()['engine_rounds']`` counts exactly the rounds the host
+    schedule predicts, in both routing modes; under key skew (every change
+    on one shard, multi-round drains) a chunk needs several rounds."""
+    stream = _skew_stream(60) if skewed else _stream(seed=41)
+    cfg = _cfg()
+    kw = dict(lane_cap=2) if skewed and routing == "device" else {}
+    ss = ShardedSummarizer(cfg, routing=routing, n_shards=2,
+                           router_chunk=64, **kw)
+    n_chunks = 0
+    for off in range(0, len(stream), 64):
+        ss.process(stream[off:off + 64])
+        n_chunks += 1
+    st = ss.stats()
+    want = _host_round_schedule(stream, 2, 64, cfg.batch)
+    assert st["engine_rounds"] == want
+    assert st["router_overflows"] == 0
+    if skewed:
+        assert want > n_chunks
+    assert ss.live_edges() == ground_truth_edges(stream)
+
+
+def test_engine_rounds_under_skew_on_4_virtual_devices_subprocess():
+    """On a real 4-device mesh under forced key skew the pmax-agreed round
+    count exceeds the chunk count and equals the host schedule, in both
+    routing modes."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.core.engine import EngineConfig, ShardedSummarizer
+        from repro.dist.labelhash import hash_label
+
+        assert len(jax.devices()) == 4
+        cfg = EngineConfig(n_cap=128, m_cap=1024, d_cap=32, sn_cap=24,
+                           c=4, batch=8, escape=0.3)
+        leaves = ["x%03d" % i for i in range(70)]
+        lo = min(hash_label(x) for x in leaves)
+        hub = next(h for h in ("hub%d" % j for j in range(100000))
+                   if hash_label(h) < lo)
+        stream = [(hub, x, True) for x in leaves]
+        stream += [(hub, x, False) for x in leaves[::3]]
+        want = 0
+        for off in range(0, len(stream), 64):
+            keys = [min(hash_label(u), hash_label(v)) % 4
+                    for (u, v, _) in stream[off:off + 64]]
+            want += -(-int(np.bincount(keys, minlength=4).max()) // 8)
+        n_chunks = -(-len(stream) // 64)
+        dev = ShardedSummarizer(cfg, routing="device", n_shards=4,
+                                router_chunk=64, lane_cap=4)
+        host = ShardedSummarizer(cfg, routing="host", n_shards=4,
+                                 router_chunk=64)
+        assert dev.router_geometry.n_dev == 4
+        for off in range(0, len(stream), 64):
+            dev.process(stream[off:off + 64])
+            host.process(stream[off:off + 64])
+        sd, sh = dev.stats(), host.stats()
+        assert sd["router_drain_rounds"] >= 2, sd
+        assert sd["engine_rounds"] == sh["engine_rounds"] == want, (sd, sh)
+        assert want > n_chunks, (want, n_chunks)
+        print("4-device engine rounds OK:", want, "rounds,", n_chunks,
+              "chunks")
+    """)
+    out = subprocess.run([sys.executable, "-c", code],
+                         capture_output=True, text=True, env=env,
+                         timeout=900)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "OK" in out.stdout
+
+
+def test_dispatch_records_spans_without_device_fetches(monkeypatch):
+    """With the span recorder on, steady-state ``process()`` still fetches
+    nothing from the device (reading any array's value raises inside the
+    dispatch loop, as does a transfer under the guard on backends that
+    enforce it) and records its spans: one ``summarizer.process`` root
+    per call, its children tagged with the chunk's journal sequence
+    number."""
+    import jax
+    from jax._src import array as jax_array
+
+    stream = _stream(seed=91)
+    ss = ShardedSummarizer(_cfg(), routing="device", n_shards=2,
+                           router_chunk=64)
+    assert ss.sync_free and ss.pipeline and ss.obs.enabled
+    ss.process(stream[:64])                 # compiles outside the guard
+    ss.process(stream[64:128])
+    calls = 2
+
+    def fetch(*_):
+        raise AssertionError("device fetch inside process()")
+
+    with monkeypatch.context() as m:
+        m.setattr(jax_array.ArrayImpl, "_value", property(fetch))
+        m.setattr(jax, "device_get", fetch)
+        with jax.transfer_guard_device_to_host("disallow"):
+            for off in range(128, len(stream), 64):
+                ss.process(stream[off:off + 64])
+                calls += 1
+    spans = ss.obs.spans("summarizer.")
+    roots = [s for s in spans if s.parent_id == 0]
+    assert [s.name for s in roots] == ["summarizer.process"] * calls
+    assert [s.request_id for s in roots] == list(range(calls))
+    by_id = {s.span_id: s for s in spans}
+    kids = [s for s in spans if s.parent_id]
+    assert {s.name for s in kids} == {
+        "summarizer.journal", "summarizer.pack", "summarizer.route",
+        "summarizer.engine"}
+    for s in kids:
+        parent = by_id[s.parent_id]
+        assert parent.name == "summarizer.process"
+        assert parent.start_ns <= s.start_ns <= s.end_ns <= parent.end_ns
+        # the pipelined engine stage runs the previous chunk
+        want = parent.request_id - (s.name == "summarizer.engine")
+        assert s.request_id == want, s
+    st = ss.stats()
+    assert st["router_syncs"] == 0 and st["router_host_dict_ops"] == 0
+    assert [s.name for s in ss.obs.spans("summarizer.sync")] == [
+        "summarizer.sync"]
+    assert ss.live_edges() == ground_truth_edges(stream)
+
+
+def test_stage_programs_counts_each_jitted_stage():
+    stream = _stream(seed=41)
+    ss = ShardedSummarizer(_cfg(), routing="device", n_shards=2,
+                           router_chunk=64)
+    ss.run(stream)
+    progs = ss.stats()["stage_programs"]
+    assert set(progs) == {"route", "engine", "query"}
+    assert progs["route"] >= 1 and progs["engine"] >= 1
+    before = progs["query"]
+    view = ss.query()
+    view.degree_batch([stream[0][0]])
+    assert ss.stats()["stage_programs"]["query"] >= max(before, 1)
+    host = ShardedSummarizer(_cfg(), routing="host", n_shards=2,
+                             router_chunk=64).run(stream)
+    hp = host.stats()["stage_programs"]
+    assert hp["route"] == 0 and hp["engine"] >= 1
+
+
+def test_named_scopes_keep_module_names():
+    """The stages carry named scopes in their op metadata, and the jitted
+    programs keep the module names the benchmark's trace reduction keys
+    on (``jit_local`` for the route and engine stages)."""
+    from repro.serve.query import make_sharded_query_kernels
+
+    ss = ShardedSummarizer(_cfg(), routing="device", n_shards=2,
+                           router_chunk=64)
+    packed = ss._pack_chunk(_stream(seed=41)[:64], pad_to=64)
+    route = ss._route.lower(*packed).as_text(debug_info=True)
+    *buckets, counts, _, rounds = ss._route(*packed)
+    engine = ss._engine.lower(ss.state, ss.intern, ss._drain_rounds,
+                              *buckets, counts, rounds).as_text(
+                                  debug_info=True)
+    assert "module @jit_local" in route and "module @jit_local" in engine
+    for scope in ("route/keys", "route/drain"):
+        assert scope in route, scope
+    # op locations hold each scope at the head of a name path
+    for scope in ("engine/intern", "engine/round", '"apply/',
+                  '"trial_group/', '"plan/', '"eval_phi/', '"commit/'):
+        assert scope in engine, scope
+    k = make_sharded_query_kernels(ss.cfg, ss.mesh, ss.trial_backend)
+    q = np.zeros(8, np.int32)
+    for fn, name, n_q in ((k.neighbors, "nbrs_local", 2),
+                          (k.degree, "deg_local", 2),
+                          (k.has_edge, "he_local", 4)):
+        text = fn.lower(ss.state, ss.intern, *(q,) * n_q).as_text(
+            debug_info=True)
+        assert f"module @jit_{name}" in text
+        assert "query/resolve" in text and "query/scan" in text

@@ -99,7 +99,8 @@ def test_route_and_engine_stages_compile(one_chip_mesh, cfg):
     engine = R.make_engine_step(cfg, mesh, N_SHARDS, geom.acc_cap,
                                 replica_exec="vmap", trial_backend="xla")
     est, ist = _states(cfg, mesh)
-    args = ((est, ist, i32(1)) + (i32(N_SHARDS, geom.acc_cap),) * 5
+    # telemetry: one row per device, (drain rounds, engine rounds)
+    args = ((est, ist, i32(1, 2)) + (i32(N_SHARDS, geom.acc_cap),) * 5
             + (i32(N_SHARDS), i32(1)))
     compiled = engine.lower(*args).compile()
     _fits(compiled)
