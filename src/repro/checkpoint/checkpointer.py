@@ -166,11 +166,15 @@ def delete_step(ckpt_dir: str, step: int) -> None:
         shutil.rmtree(path)
 
 
-def restore(ckpt_dir: str, step: int, like, shardings=None):
+def restore(ckpt_dir: str, step: int, like, shardings=None,
+            zero_if_absent=()):
     """Restore into the structure of ``like``; reshard under ``shardings``.
 
     ``shardings`` may target a different mesh than the one that saved —
-    leaves are device_put with the new sharding (elastic restart).
+    leaves are device_put with the new sharding (elastic restart).  A leaf
+    whose key is in ``zero_if_absent`` and that the checkpoint lacks (it
+    was written before the leaf existed) restores as zeros of ``like``'s
+    shape and dtype; any other missing leaf raises ``KeyError``.
     """
     path = os.path.join(ckpt_dir, f"step_{step:08d}")
     data = np.load(os.path.join(path, "arrays.npz"))
@@ -180,7 +184,10 @@ def restore(ckpt_dir: str, step: int, like, shardings=None):
     leaves = []
     for (p, leaf), sh in zip(flat, shard_flat):
         key = _path_key(p)
-        arr = data[key]
+        if key in zero_if_absent and key not in data.files:
+            arr = np.zeros(leaf.shape, leaf.dtype)
+        else:
+            arr = data[key]
         assert arr.shape == tuple(leaf.shape), f"shape mismatch at {key}"
         if sh is not None:
             leaves.append(jax.device_put(arr, sh))

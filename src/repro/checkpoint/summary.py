@@ -55,6 +55,9 @@ from repro.checkpoint import checkpointer
 from repro.checkpoint.journal import ChunkJournal
 
 CKPT_CLOSURE_VERSION = 1
+# counters added to the engine state after closure version 1: a checkpoint
+# written before them restores them at 0
+LEAVES_RESTORED_AT_ZERO = ("est/n_passes",)
 KEEP_EPOCHS = 2     # checkpoint fallback depth (journal covers the span)
 
 
@@ -140,7 +143,8 @@ def restore_summarizer(summ, ckpt_dir: str,
         extra = checkpointer.load_meta(ckpt_dir, s).get("extra", {})
         _check_manifest(summ, extra)
         tree = checkpointer.restore(ckpt_dir, s, like=summ._ckpt_tree(),
-                                    shardings=summ._ckpt_shardings())
+                                    shardings=summ._ckpt_shardings(),
+                                    zero_if_absent=LEAVES_RESTORED_AT_ZERO)
         host = pickle.loads(checkpointer.load_blob(ckpt_dir, s, "host.pkl"))
         summ._ckpt_apply(tree, host, extra)
         return dict(step=s, epoch=int(extra["epoch"]),

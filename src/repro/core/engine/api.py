@@ -1104,9 +1104,12 @@ class ShardedSummarizer(_CrashConsistency):
         ``router_host_dict_ops`` counts label-map mutations inside
         dispatch (0 on the hash-routed path).  ``engine_rounds`` counts
         the engine rounds run (each ``n_shards x batch`` slots, filled or
-        not), and ``stage_programs`` the compiled programs each jitted
-        stage holds (``{"route", "engine", "query"}``; ``None`` where the
-        JAX version cannot tell).  One device transfer (counters only) —
+        not), ``trial_passes`` the speculative trial passes summed over
+        shards (``core/engine/trial.py``; about one per live trial group
+        plus one per commit; ``None`` in the ``map`` layout, which runs
+        the serial trial loop), and ``stage_programs`` the compiled
+        programs each jitted stage holds (``{"route", "engine",
+        "query"}``; ``None`` where the JAX version cannot tell).  One device transfer (counters only) —
         this is a sync point."""
         import jax
 
@@ -1115,14 +1118,17 @@ class ShardedSummarizer(_CrashConsistency):
         self._fold_labels()
         s = self.state
         with self.obs.span("summarizer.sync"):
-            phi, ne, tr, ac, sk, dr, telem = jax.device_get(
+            phi, ne, tr, ac, sk, tpass, dr, telem = jax.device_get(
                 (s.phi, s.num_edges, s.n_trials, s.n_accept, s.n_skipped,
-                 self.intern.n_dropped, self._drain_rounds))
+                 s.n_passes, self.intern.n_dropped, self._drain_rounds))
         self._raise_if_dropped(int(np.sum(dr)))
         tot = lambda x: int(np.sum(x))  # noqa: E731
         return dict(phi=tot(phi), num_edges=tot(ne),
                     trials=tot(tr), accepted=tot(ac),
-                    skipped=tot(sk), n_shards=self.n_shards,
+                    skipped=tot(sk),
+                    trial_passes=(tot(tpass) if self.replica_exec == "vmap"
+                                  else None),
+                    n_shards=self.n_shards,
                     routing=self.routing,
                     router_overflows=self.router_overflows,
                     # engine-stage carried telemetry: every device carries

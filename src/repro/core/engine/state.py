@@ -158,6 +158,8 @@ class EngineState(NamedTuple):
     n_trials: jax.Array
     n_accept: jax.Array
     n_skipped: jax.Array  # trials skipped by capacity guards (deviation audit)
+    n_passes: jax.Array   # speculative trial passes run (trial.py, dense
+    #                       lowering only; a checkpoint without it restores 0)
 
 
 def new_state(cfg: EngineConfig) -> EngineState:
@@ -186,4 +188,5 @@ def new_state(cfg: EngineConfig) -> EngineState:
         n_trials=jnp.int32(0),
         n_accept=jnp.int32(0),
         n_skipped=jnp.int32(0),
+        n_passes=jnp.int32(0),
     )

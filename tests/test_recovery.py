@@ -225,6 +225,47 @@ def test_closure_without_engine_round_column_restores_it_at_zero():
     assert got == want
 
 
+_WITHOUT_PASSES = {
+    "batched": _batched,
+    "sharded-map": lambda d=None: _sharded(d, replica_exec="map"),
+    "sharded-vmap": lambda d=None: _sharded(d, replica_exec="vmap"),
+}
+
+
+@pytest.mark.parametrize("make", list(_WITHOUT_PASSES))
+def test_checkpoint_without_trial_passes_restores_them_at_zero(tmp_path,
+                                                               make):
+    """A checkpoint written before the engine state's ``n_passes`` leaf
+    recovers with that count at 0 and every other leaf and counter
+    bitwise unchanged — in both summarizers and both replica layouts."""
+    make = _WITHOUT_PASSES[make]
+    stream = _stream(96)
+    d = str(tmp_path)
+    ref = make(d)
+    inject.drive(ref, stream)
+    ref.flush()
+    full = ref._ckpt_tree()
+    old = {k: dict(v) for k, v in full.items()}
+    del old["est"]["n_passes"]
+    ref._ckpt_tree = lambda: old
+    ref.save()
+    del ref._ckpt_tree
+    assert "est/n_passes" not in np.load(os.path.join(
+        d, f"step_{checkpointer.latest_step(d):08d}", "arrays.npz")).files
+    rec = make(d)
+    info = rec.recover()
+    assert info["replayed_chunks"] == 0
+    got = rec._ckpt_tree()
+    assert not np.asarray(got["est"]["n_passes"]).any()
+    got["est"]["n_passes"] = full["est"]["n_passes"]
+    assert_leaves_equal(got, full)
+    want, have = ref.stats(), rec.stats()
+    if want.get("trial_passes") is not None:
+        assert want["trial_passes"] > 0 and have["trial_passes"] == 0
+        want.pop("trial_passes"), have.pop("trial_passes")
+    assert have == want
+
+
 # --------------------------------------------------------------------------- #
 # checkpoint faults
 # --------------------------------------------------------------------------- #

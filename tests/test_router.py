@@ -492,9 +492,14 @@ def test_replica_exec_vmap_vs_map_vs_host_bitwise_on_key_skew():
         mp.process(stream[off:off + 64])
         host.process(stream[off:off + 64])
     assert vm.stats()["router_drain_rounds"] >= 2   # genuinely multi-round
+    assert vm.stats()["trial_passes"] > 0
+    assert mp.stats()["trial_passes"] is None
     for other in (mp, host):
         assert vm.shard_phis() == other.shard_phis()
         for a, b in zip(vm.host_states(), other.host_states()):
+            if other is mp:     # the map layout runs no speculative pass
+                assert int(b.n_passes) == 0
+                b = b._replace(n_passes=a.n_passes)
             for name, al, bl in zip(a._fields, a, b):
                 np.testing.assert_array_equal(
                     np.asarray(al), np.asarray(bl), err_msg=name)
